@@ -72,6 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import ROOT, _steady_timing, emit
+from repro.launch import compile_cache
 from repro.obs.log import configure_logging, get_logger
 
 log = get_logger("benchmarks.engine_bench")
@@ -635,6 +636,7 @@ def main() -> None:
                     help="debug-level logging")
     args = ap.parse_args()
     configure_logging(verbosity=args.verbose, quiet=args.quiet)
+    compile_cache.configure()
     scales = (tuple(int(s) for s in args.scales.split(","))
               if args.scales else SCALES)
     run(scales=scales,

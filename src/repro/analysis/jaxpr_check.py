@@ -43,14 +43,12 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-
-try:  # jax >= 0.4.16 moved core types under jax.extend
-    from jax.extend import core as jcore
-except ImportError:  # pragma: no cover - older jax
-    from jax import core as jcore
+from jax.extend import core as jcore
 
 # primitives that imply a host round-trip inside the traced program
-FORBIDDEN_PRIMS = ("pure_callback", "io_callback", "debug_callback")
+# (`jax.debug.print` traces to its own `debug_print` primitive)
+FORBIDDEN_PRIMS = ("pure_callback", "io_callback", "debug_callback",
+                   "debug_print")
 
 F64_DTYPES = (jnp.float64, jnp.complex128)
 

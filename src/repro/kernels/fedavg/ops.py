@@ -1,27 +1,23 @@
 """Jit'd public op: shape-generic weighted aggregation with backend dispatch.
 
-TPU backends run the Pallas kernel (VMEM-tiled); CPU (this container, and
-the FL simulation) uses the pure-jnp oracle — identical math, verified by
-tests/test_kernels_fedavg.py in interpret mode across shape/dtype sweeps.
+TPU backends run the Pallas kernel (VMEM-tiled); CPU uses the pure-jnp
+oracle — identical math, verified by tests/test_kernels_fedavg.py in
+interpret mode across shape/dtype sweeps.
 """
 from __future__ import annotations
 
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.fedavg import fedavg as kernel
 from repro.kernels.fedavg import ref
+from repro.kernels.mesh import replicated
 
 
 def _use_pallas() -> bool:
-    if os.environ.get("REPRO_FORCE_PALLAS"):
-        return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def weighted_aggregate(stack: jax.Array, weights: jax.Array,
@@ -48,9 +44,9 @@ def weighted_aggregate(stack: jax.Array, weights: jax.Array,
     pad = (-P) % bp
     if pad:
         flat = jnp.pad(flat, ((0, 0), (0, pad)))
-    out = kernel.weighted_aggregate_flat(flat, weights,
-                                         interpret=bool(interpret),
-                                         block_p=bp)
+    out = replicated(functools.partial(
+        kernel.weighted_aggregate_flat, interpret=bool(interpret),
+        block_p=bp))(flat, weights)
     if pad:
         out = out[:P]
     return out.reshape(stack.shape[1:]).astype(stack.dtype)

@@ -12,12 +12,10 @@
           emission in `core.selection` serves the same masks from a
           single `lax.top_k` — either way no (S,) rank sort and no dense
           (S, P) masked reduction.
-  auto    resolves to pallas on TPU (or under REPRO_FORCE_PALLAS, the
-          `kernels/fedavg` convention), else xla.
+  auto    resolves to pallas on a TPU, else xla.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -26,6 +24,7 @@ import jax.numpy as jnp
 from repro.core import selection as sel
 from repro.core import utility as util
 from repro.kernels.fedavg import ops as fedavg_ops
+from repro.kernels.mesh import replicated
 from repro.kernels.rewafl_select import ref
 from repro.kernels.rewafl_select import rewafl_select as kernel
 
@@ -34,25 +33,17 @@ TILED_MIN_S = 100_000  # below this the flat single-tile variant wins
 
 
 def resolve_backend(backend: str) -> str:
-    """'auto' → 'pallas' iff a TPU is attached (or REPRO_FORCE_PALLAS)."""
+    """'auto' → 'pallas' iff the default backend is a TPU."""
     if backend not in BACKENDS:
         raise ValueError(
             f"kernel_backend must be one of {BACKENDS}, got {backend!r}")
     if backend != "auto":
         return backend
-    if os.environ.get("REPRO_FORCE_PALLAS"):
-        return "pallas"
-    try:
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    except Exception:  # pragma: no cover
-        return "xla"
+    return "pallas" if _kernel_lowerable() else "xla"
 
 
 def _kernel_lowerable() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _round_up(x: int, m: int) -> int:
@@ -64,7 +55,8 @@ def _run_kernel(ui: util.UtilityInputs, available: jax.Array,
                 T_round: float, alpha: float, beta: float,
                 interpret: bool) -> Tuple[jax.Array, jax.Array]:
     """Pad leaves to the tile grid and run the fused selection kernel
-    (flat below TILED_MIN_S, tiled at/above it)."""
+    (flat below TILED_MIN_S, tiled at/above it); replicated on every
+    device when the fleet is sharded (`kernels.mesh.replicated`)."""
     S = available.shape[-1]
     bs = kernel.BLOCK_S if S >= TILED_MIN_S else _round_up(S, 128)
     pad = _round_up(S, bs) - S
@@ -72,12 +64,15 @@ def _run_kernel(ui: util.UtilityInputs, available: jax.Array,
     def p(x, v=0.0):
         return jnp.pad(x, (0, pad), constant_values=v) if pad else x
 
-    return kernel.select_topk(
+    def run(*leaves):
+        return kernel.select_topk(
+            *leaves, k_exploit=k_exploit, k_explore=k_explore,
+            T_round=float(T_round), alpha=float(alpha),
+            beta=float(beta), block_s=bs, interpret=interpret)
+
+    return replicated(run)(
         p(ui.stat), p(ui.t, 1.0), p(ui.e, 1.0), p(ui.residual),
-        p(ui.e0), p(available.astype(jnp.float32)), p(rnd),
-        k_exploit=k_exploit, k_explore=k_explore,
-        T_round=float(T_round), alpha=float(alpha), beta=float(beta),
-        block_s=bs, interpret=interpret)
+        p(ui.e0), p(available.astype(jnp.float32)), p(rnd))
 
 
 def _mask_from_slots(idx: jax.Array, live: jax.Array,

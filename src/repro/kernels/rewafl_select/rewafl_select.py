@@ -114,34 +114,38 @@ def _kernel(stat_ref, t_ref, e_ref, res_ref, e0_ref, avail_ref, rnd_ref,
 
     @pl.when(i == n_tiles - 1)
     def _resolve():
+        # halves with no slots are left out at trace time (Mosaic has no
+        # zero-width vectors) and live flags stay int32 (it cannot
+        # truncate a materialised bool vector)
+        halves_i, halves_l = [], []
         if k_exploit > 0:
-            xvv, xii = xv[...], xi[...]
-            x_live = xvv > LIVE_THR
-        else:
-            xii = jnp.zeros((1, 0), jnp.int32)
-            x_live = jnp.zeros((1, 0), bool)
+            xii = xi[...]
+            x_live = (xv[...] > LIVE_THR).astype(jnp.int32)
+            halves_i.append(xii)
+            halves_l.append(x_live)
         if k_explore > 0:
+            rvv, rii = rv[...], ri[...]
+            iota_c = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+            iota_r = jax.lax.broadcasted_iota(jnp.int32, (1, k_explore), 1)
             r_idx = jnp.zeros((1, k_explore), jnp.int32)
-            r_live = jnp.zeros((1, k_explore), bool)
-            iota_r = jax.lax.broadcasted_iota(jnp.int32,
-                                              (1, k_explore), 1)
+            r_live = jnp.zeros((1, k_explore), jnp.int32)
             cnt = jnp.int32(0)
             for m in range(k):
-                g = ri[0, m]
-                live = rv[0, m] > LIVE_THR
-                taken = (jnp.any((xii == g) & x_live)
-                         if k_exploit > 0 else False)
-                pick = live & ~taken & (cnt < k_explore)
-                slot = (iota_r == cnt) & pick
+                at = iota_c == m
+                g = jnp.sum(jnp.where(at, rii, 0))
+                live = jnp.max(jnp.where(at, rvv, NEG)) > LIVE_THR
+                pick = live & (cnt < k_explore)
+                if k_exploit > 0:
+                    taken = jnp.max(jnp.where(xii == g, x_live, 0)) > 0
+                    pick = pick & ~taken
+                slot = iota_r == jnp.where(pick, cnt, -1)
                 r_idx = jnp.where(slot, g, r_idx)
-                r_live = jnp.where(slot, True, r_live)
+                r_live = jnp.where(slot, 1, r_live)
                 cnt = cnt + pick.astype(jnp.int32)
-        else:
-            r_idx = jnp.zeros((1, 0), jnp.int32)
-            r_live = jnp.zeros((1, 0), bool)
-        oidx_ref[...] = jnp.concatenate([xii, r_idx], axis=-1)[0]
-        olive_ref[...] = jnp.concatenate(
-            [x_live, r_live], axis=-1)[0].astype(jnp.int32)
+            halves_i.append(r_idx)
+            halves_l.append(r_live)
+        oidx_ref[...] = jnp.concatenate(halves_i, axis=-1)[0]
+        olive_ref[...] = jnp.concatenate(halves_l, axis=-1)[0]
 
 
 @functools.partial(jax.jit, static_argnames=(

@@ -57,6 +57,7 @@ Layers (each usable on its own):
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import time
@@ -618,6 +619,9 @@ def run_rounds(model: FLModel, fleet: DeviceFleet, cx, cy, cfg: FLConfig,
         params = _copy_tree(params)
         state = _copy_tree(state)
 
+    # chunks trace under the fleet mesh, so the Pallas kernels (which
+    # GSPMD cannot partition) run replicated (`kernels.mesh.replicated`)
+    mesh = None
     if ecfg.fleet_shards and ecfg.fleet_shards > 1:
         mesh = make_fleet_mesh(ecfg.fleet_shards)
         fleet = shard_over_fleet(fleet, mesh, S)
@@ -706,7 +710,9 @@ def run_rounds(model: FLModel, fleet: DeviceFleet, cx, cy, cfg: FLConfig,
                     else (params, state))
             args = lead + (env, fleet, cx, cy, key, jnp.asarray(done,
                                                                 jnp.int32))
-            with span("compile" if fresh else "dispatch", ci):
+            on_mesh = (jax.set_mesh(mesh) if mesh is not None
+                       else contextlib.nullcontext())
+            with span("compile" if fresh else "dispatch", ci), on_mesh:
                 out = chunk_fn(length)(*args
                                        + ((tel,) if streaming else ()))
             params, state = out[0], out[1]

@@ -34,6 +34,7 @@ from repro.core import (METHODS, FLConfig, init_fleet_state, make_eval_fn,
 from repro.data.partition import client_datasets
 from repro.data.synthetic import (make_char_dataset, make_har_dataset,
                                   make_image_dataset)
+from repro.launch import compile_cache
 from repro.models.fl_models import make_fl_model
 from repro.obs.health import HealthCfg, HealthReport, format_health_table
 from repro.obs.log import configure_logging, get_logger
@@ -143,6 +144,11 @@ def quick_cfg(n_select: int = 20, alpha: float = 1.0,
                     uplink_bits=40e6,
                     policy=PolicyCfg(H0=5, H_max=16, dH=1.5))
 
+
+# run_fl's fleet: the paper's low-initial-battery regime (Fig. 1 / Fig. 4
+# use 6–30 kJ initial energies, not full batteries)
+FLEET_KWARGS = {"init_energy_mean": 0.11, "init_energy_std": 0.04,
+                "e0_frac": 0.08}
 
 HIST_KEYS = ("round_latency", "round_energy", "n_dropped",
              "n_participating", "n_failed", "mean_H_selected", "global_loss",
@@ -263,11 +269,8 @@ def run_fl(task: str = "cnn@mnist", method: str = "rewafl", *,
         return res
     model = make_fl_model(task, small=small)
     scen = get_scenario(scenario)
-    # benchmark-scale default: the paper's low-initial-battery regime
-    # (Fig. 1 / Fig. 4 use 6–30 kJ initial energies, not full batteries)
-    fkw = {"init_energy_mean": 0.11, "init_energy_std": 0.04, "e0_frac": 0.08}
-    fkw.update(fleet_kwargs or {})
-    fleet = build_fleet(n_clients, seed=seed, **fkw)
+    fleet = build_fleet(n_clients, seed=seed,
+                        **(FLEET_KWARGS | (fleet_kwargs or {})))
     cx, cy, test = build_task(task, n_clients, lam, per_client=per_client,
                               seed=seed)
     cfg = fl_cfg or (quick_cfg(n_select, alpha, beta) if small else
@@ -536,6 +539,7 @@ def main() -> None:
                     help="debug-level logging")
     args = ap.parse_args()
     configure_logging(verbosity=args.verbose, quiet=args.quiet)
+    compile_cache.configure()
     hcfg = (HealthCfg(max_flat_frac=args.max_flat_frac,
                       max_near_frac=args.max_near_frac)
             if args.health or args.health_strict else None)

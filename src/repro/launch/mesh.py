@@ -32,6 +32,9 @@ def make_host_mesh(shape=(2, 2), axes=("data", "model")) -> ShardCfg:
 def make_fleet_mesh(n_shards=None):
     """1-D mesh over the FL fleet axis S (axis name "fleet") — the engine
     shards every (S, ...) array over it; selection top-k and the K-slot
-    gathers stay global ops partitioned by GSPMD."""
+    gathers stay global ops partitioned by GSPMD. The axis is `Auto`:
+    with `Explicit` (jax.make_mesh's default) every gather over the
+    sharded axis would need a hand-written output sharding."""
     n = n_shards or len(jax.devices())
-    return jax.make_mesh((n,), ("fleet",))
+    return jax.make_mesh((n,), ("fleet",),
+                         axis_types=(jax.sharding.AxisType.Auto,))

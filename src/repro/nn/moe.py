@@ -20,7 +20,6 @@ the local shard and pmean'd across the mesh in the sharded path).
 from __future__ import annotations
 
 import dataclasses
-import inspect
 import math
 
 import jax
@@ -28,24 +27,6 @@ import jax.numpy as jnp
 
 from repro.nn import layers
 from repro.nn.sharding import ShardCfg
-
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-try:  # jax < 0.6 spells the replication-check kwarg ``check_rep``
-    _CHECK_KW = ("check_vma" if "check_vma" in inspect.signature(
-        _shard_map).parameters else "check_rep")
-except (TypeError, ValueError):  # pragma: no cover — unintrospectable
-    _CHECK_KW = "check_vma"
-
-
-def shard_map(*args, **kwargs):
-    if "check_vma" in kwargs and _CHECK_KW != "check_vma":
-        kwargs[_CHECK_KW] = kwargs.pop("check_vma")
-    return _shard_map(*args, **kwargs)
-
 
 @dataclasses.dataclass(frozen=True)
 class MoECfg:
@@ -192,12 +173,12 @@ def moe_forward_sharded(params, x: jax.Array, cfg: MoECfg, sc: ShardCfg):
 
     shared = params.get("shared")
     if shared is None:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda r, e, xl: local_moe(r, e, None, xl), mesh=sc.mesh,
             in_specs=(rep, expert_spec, x_spec), out_specs=(x_spec, rep),
             check_vma=False)
         return fn(params["router"], params["experts"], x)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_moe, mesh=sc.mesh,
         in_specs=(rep, expert_spec, rep, x_spec),
         out_specs=(x_spec, rep),
@@ -280,7 +261,7 @@ def moe_forward_sharded_2d(params, x: jax.Array, cfg: MoECfg, sc: ShardCfg):
                 jax.sharding.PartitionSpec(None, model_axis),
                 jax.sharding.PartitionSpec(model_axis, None))
     if shared is None:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda r, wg, wu, wd, xl: local_moe(r, wg, wu, wd, None, None,
                                                 None, xl),
             mesh=sc.mesh,
@@ -288,7 +269,7 @@ def moe_forward_sharded_2d(params, x: jax.Array, cfg: MoECfg, sc: ShardCfg):
             out_specs=(x_spec, rep), check_vma=False)
         e = params["experts"]
         return fn(params["router"], e["w_gate"], e["w_up"], e["w_down"], x)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_moe, mesh=sc.mesh,
         in_specs=(rep, gate_spec, gate_spec, down_spec) + sh_specs + (x_spec,),
         out_specs=(x_spec, rep), check_vma=False)

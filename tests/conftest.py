@@ -11,7 +11,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # XLA work. Must be configured before the first jax computation.
 import jax  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir",
-                  os.environ.get("REPRO_JAX_CACHE_DIR",
-                                 "/tmp/repro_jax_cache"))
+from repro.launch import compile_cache  # noqa: E402
+
+compile_cache.configure()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)

@@ -244,14 +244,14 @@ def test_debug_print_caught_inside_scan():
         return y
 
     jx = jax.make_jaxpr(chunk)(jnp.float32(0.0))
-    assert "debug_callback" in forbidden_prims(jx.jaxpr)
+    assert "debug_print" in forbidden_prims(jx.jaxpr)
 
 
 def test_f64_aval_scan():
     def f(x):
         return x.astype("float64") * 2.0
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jx = jax.make_jaxpr(f)(jnp.ones((2,), jnp.float32))
     assert f64_avals(jx.jaxpr)
     jx32 = jax.make_jaxpr(lambda x: x * 2.0)(jnp.ones((2,), jnp.float32))
