@@ -82,7 +82,7 @@ from repro.models.fl_models import FLModel
 from repro.obs.health import (HealthCfg, HealthReport, chunk_sample,
                               finalize_report, with_health_specs)
 from repro.obs.log import get_logger
-from repro.obs.trace import span
+from repro.obs.trace import span, until_ready
 from repro.sim.devices import DeviceFleet
 from repro.sim.dynamics import EnvState, Scenario, init_env_state
 from repro.training import checkpoint as ckpt
@@ -705,7 +705,7 @@ def run_rounds(model: FLModel, fleet: DeviceFleet, cx, cy, cfg: FLConfig,
         length = min(ecfg.chunk_size, rounds - done)
         fresh = length not in chunk_fns
         t0 = time.time()
-        with span("chunk", ci, rounds=length, start=done):
+        with span("chunk", ci, rounds=length, start=done) as chunk_span:
             lead = ((params, state, astate) if acfg is not None
                     else (params, state))
             args = lead + (env, fleet, cx, cy, key, jnp.asarray(done,
@@ -715,6 +715,8 @@ def run_rounds(model: FLModel, fleet: DeviceFleet, cx, cy, cfg: FLConfig,
             with span("compile" if fresh else "dispatch", ci), on_mesh:
                 out = chunk_fn(length)(*args
                                        + ((tel,) if streaming else ()))
+            # the history is never donated, unlike the carry
+            until_ready("chunk.device", out[-1], chunk_span, index=ci)
             params, state = out[0], out[1]
             i = 2
             if acfg is not None:
@@ -951,13 +953,15 @@ def run_campaign_batch(model: FLModel, fleet: DeviceFleet, cx, cy,
                 in_axes=in_axes))
             fresh = True
         t0 = time.time()
-        with span("chunk", ci, rounds=length, start=done, seeds=B):
+        with span("chunk", ci, rounds=length, start=done,
+                  seeds=B) as chunk_span:
             lead = ((params, state, astate) if is_async
                     else (params, state))
             args = lead + (env, fleet, cx, cy, keys,
                            jnp.asarray(done, jnp.int32))
             with span("compile" if fresh else "dispatch", ci):
                 out = batched(*args + ((tel,) if streaming else ()))
+            until_ready("chunk.device", out[-1], chunk_span, index=ci)
             params, state = out[0], out[1]
             i = 2
             if is_async:
@@ -1167,13 +1171,15 @@ def _run_grid_batched(model: FLModel, fleet: DeviceFleet, cx, cy,
             batched = grid_fn(length)
             fresh = True
         t0 = time.time()
-        with span("chunk", ci, rounds=length, start=done, cells=M * B):
+        with span("chunk", ci, rounds=length, start=done,
+                  cells=M * B) as chunk_span:
             lead = (mp_cells, seed_idx, params, state) + (
                 (astate,) if any_async else ())
             args = lead + (env, fleet, cx, cy, keys,
                            jnp.asarray(done, jnp.int32))
             with span("compile" if fresh else "dispatch", ci):
                 out = batched(*args + ((tel,) if streaming else ()))
+            until_ready("chunk.device", out[-1], chunk_span, index=ci)
             params, state = out[0], out[1]
             i = 2
             if any_async:

@@ -26,8 +26,8 @@ from repro.obs.health import (HealthCfg, HealthReport, chunk_sample,
                               with_health_specs)
 from repro.obs.log import configure_logging, get_logger
 from repro.obs.trace import (NullTracer, Tracer, _NULL_SPAN,
-                             format_span_table, get_tracer, set_tracer,
-                             span, tracing)
+                             format_span_table, get_tracer, last_tracer,
+                             set_tracer, span, tracing)
 from repro.sim.devices import build_fleet
 from repro.sim.dynamics import get_scenario
 
@@ -67,15 +67,15 @@ def test_chrome_json_round_trip(tmp_path):
     with t.span("a", 0):
         with t.span("b"):
             pass
-    t.instant("marker", note="hi")
     path = tmp_path / "out.trace.json"
     t.write(str(path))
     d = json.loads(path.read_text())
     assert d["displayTimeUnit"] == "ms"
+    assert d["otherData"]["unix_ns_at_ts0"] == t.unix_ns_at_ts0
     evs = d["traceEvents"]
-    assert {e["name"] for e in evs} == {"a", "b", "marker"}
+    assert {e["name"] for e in evs} == {"a", "b"}
     assert all("ts" in e and "pid" in e and "tid" in e for e in evs)
-    assert [e["ph"] for e in evs if e["name"] == "marker"] == ["i"]
+    assert len({e["id"] for e in evs}) == 2
 
 
 def test_summary_aggregates_per_name():
@@ -112,7 +112,102 @@ def test_null_tracer_is_shared_singleton():
     assert nt.span("a") is nt.span("b") is _NULL_SPAN
     assert not nt.enabled and Tracer().enabled
     assert nt.events == [] and nt.summary() == {}
-    nt.instant("x")  # no-op, no error
+    assert nt.record("x", 0.0, 1.0) is None  # no-op, no error
+
+
+def test_record_lands_where_a_span_over_the_same_interval_does():
+    """`record()` maps `time.time()` seconds onto the span clock through
+    the creation anchor: within 1 ms of a context-manager span."""
+    t = Tracer()
+    with t.span("cm"):
+        a = time.time()
+        time.sleep(0.01)
+        b = time.time()
+    i = t.record("rec", a, b, parent=7, note="x")
+    evs = {e["name"]: e for e in t.events}
+    cm, rec = evs["cm"], evs["rec"]
+    assert rec["id"] == i and rec["parent"] == 7
+    assert rec["args"] == {"note": "x"}
+    assert abs(rec["ts"] - cm["ts"]) < 1000.0
+    assert abs(rec["ts"] + rec["dur"] - cm["ts"] - cm["dur"]) < 1000.0
+
+
+def _tiny_jit_once(n):
+    def tiny_fn(x):
+        return x * n + 1.0
+    return jax.jit(tiny_fn)(jax.numpy.ones((3,))).block_until_ready()
+
+
+def test_jax_compile_phases_recorded_as_spans():
+    """A fresh jit under an installed tracer yields JAX's trace, lower and
+    compile phases as spans named for its function; the compile names
+    its persistent-cache outcome."""
+    with tracing(Tracer()) as t:
+        _tiny_jit_once(3.0)
+    evs = [e for e in t.events if e["name"].startswith("jax.")]
+    by = {}
+    for e in evs:
+        by.setdefault(e["name"], []).append(e["args"])
+    assert {"jax.trace", "jax.lower", "jax.compile"} <= set(by)
+    for name in ("jax.trace", "jax.lower", "jax.compile"):
+        assert any("tiny_fn" in str(a["fun_name"]) for a in by[name])
+    assert all(a["cache"] in ("hit", "miss", "off")
+               for a in by["jax.compile"])
+    assert "compile" not in {e["name"] for e in t.events}
+
+
+def test_null_tracer_records_no_compile_phases():
+    """With the default NullTracer installed the listener forwards
+    nothing, to no tracer made before or installed after."""
+    made = Tracer()
+    prev = set_tracer(NullTracer())
+    try:
+        _tiny_jit_once(5.0)
+    finally:
+        set_tracer(prev)
+    assert made.events == []
+    with tracing(Tracer()) as later:
+        pass
+    assert later.events == []
+
+
+def test_last_tracer_outlives_its_region():
+    with tracing(Tracer()) as t:
+        pass
+    with tracing(NullTracer()):
+        pass
+    assert last_tracer() is t
+
+
+def test_xla_chunk_span_is_a_profiler_step_on_the_anchor_clock(tmp_path):
+    """With `xla=True` the `chunk` span is a profiler step on the host
+    plane, named `chunk` as before, and the tracer's anchor puts its
+    start within 1 ms of the capture's own clock."""
+    from jax.profiler import ProfileData
+    t = Tracer(xla=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with t.span("chunk", 4):
+            with t.span("dispatch", 4):
+                time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    start_ns = None
+    host = []
+    for plane in pd.planes:
+        stats = dict(plane.stats)
+        if "profile_start_time" in stats:
+            start_ns = stats["profile_start_time"]
+        if plane.name.startswith("/host"):
+            host += [e for ln in plane.lines for e in ln.events]
+    chunk = [e for e in host if e.name == "chunk"]
+    assert len(chunk) == 1 and dict(chunk[0].stats)["step_num"] == 4
+    assert [e.name for e in host if e.name == "dispatch"] == ["dispatch"]
+    ev = next(e for e in t.events if e["name"] == "chunk")
+    mine = t.unix_ns_at_ts0 + 1e3 * ev["ts"]
+    assert abs(start_ns + chunk[0].start_ns - mine) < 1e6
 
 
 def test_noop_span_overhead_is_negligible():
@@ -354,9 +449,24 @@ def test_engine_run_emits_phase_spans(setup):
     base = _health_run(setup, get_scenario("static-paper"), None)
     with tracing(Tracer()) as t:
         traced = _health_run(setup, get_scenario("static-paper"), None)
-    names = {e["name"] for e in t.events}
+    evs = t.events
+    names = {e["name"] for e in evs}
     # no eval_fn in this run, so no "eval" span
     assert {"chunk", "transfer"} <= names
     assert "compile" in names or "dispatch" in names
     np.testing.assert_array_equal(base.history["global_loss"],
                                   traced.history["global_loss"])
+    for k in base.history:
+        np.testing.assert_array_equal(base.history[k], traced.history[k])
+    # one chunk.device per chunk, under its chunk, ending no earlier than
+    # the chunk's dispatch (it starts when the dispatch returns)
+    chunks = {e["args"]["index"]: e for e in evs if e["name"] == "chunk"}
+    calls = {e["args"]["index"]: e for e in evs
+             if e["name"] in ("compile", "dispatch")}
+    dev = [e for e in evs if e["name"] == "chunk.device"]
+    assert sorted(e["args"]["index"] for e in dev) == sorted(chunks) \
+        == [0, 1]
+    for e in dev:
+        i = e["args"]["index"]
+        assert e["parent"] == chunks[i]["id"]
+        assert e["ts"] + e["dur"] >= calls[i]["ts"] + calls[i]["dur"]
