@@ -20,7 +20,7 @@ import jax.numpy as jnp
 @dataclasses.dataclass(frozen=True)
 class PolicyCfg:
     H0: int = 5
-    H_max: int = 30            # static loop bound for the masked local SGD
+    H_max: int = 30            # upper clip of the adaptive H (Eqn (3))
     dH: float = 2.0            # ΔH increment unit
     psi0: float = 1.0          # ψ scale
     s_ref: float = 20e6        # bps — rate normalisation in ψ

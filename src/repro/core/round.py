@@ -2,10 +2,11 @@
 
 Per round: sample uplink rates → per-device candidate H (policy) →
 latency/energy estimates → PS utilities → top-K selection → masked
-vmapped local SGD on the K selected clients (lax.fori_loop to the static
-H_max with per-client iteration masks — TPU-style static shapes instead
-of ragged loops) → FedAvg (Pallas-kernel-backed weighted aggregation) →
-fleet-state update (Algorithm 1 lines 18–27).
+vmapped local SGD on the K selected clients (one loop to the cohort's
+largest live H, with per-client iteration masks for the slots whose H
+is smaller — static shapes instead of ragged loops) → FedAvg
+(Pallas-kernel-backed weighted aggregation) → fleet-state update
+(Algorithm 1 lines 18–27).
 
 Method dispatch has two flavours sharing this one body:
 
@@ -112,8 +113,11 @@ def _probe_losses(model: FLModel, params, cx, cy, probe: int) -> jax.Array:
     return jnp.mean(ls, axis=1), jnp.mean(ls ** 2, axis=1)
 
 
-def _local_sgd(model: FLModel, params, x, y, H, key, cfg: FLConfig):
-    """Masked local SGD: fori_loop to H_max; iterations ≥ H are no-ops."""
+def _local_sgd(model: FLModel, params, x, y, H, n_iters, key,
+               cfg: FLConfig):
+    """Masked local SGD: `n_iters` iterations (the cohort's largest H, a
+    traced bound shared by every slot); iterations ≥ this slot's H are
+    no-ops."""
     n = x.shape[0]
     grad_fn = jax.grad(model.loss)
 
@@ -124,7 +128,7 @@ def _local_sgd(model: FLModel, params, x, y, H, key, cfg: FLConfig):
         live = (it < H).astype(jnp.float32)
         return jax.tree.map(lambda pp, gg: pp - cfg.lr * live * gg, p, g)
 
-    return jax.lax.fori_loop(0, cfg.policy.H_max, body, params)
+    return jax.lax.fori_loop(0, n_iters, body, params)
 
 
 def _fedavg(global_params, client_params, weights, backend=None):
@@ -208,11 +212,6 @@ def _build_round_body(model: FLModel, cfg: FLConfig,
     # kernels/rewafl_select (kb == "xla" reproduces the pre-kernel
     # graphs exactly — the golden-bitwise path)
     kb = rsel_ops.resolve_backend(cfg.kernel_backend)
-    if method is not None and method.policy == "fixed":
-        # fixed-H baselines never exceed H0 — shrink the static loop bound
-        # (the traced path cannot: its loop bound must cover every method)
-        cfg = dataclasses.replace(
-            cfg, policy=dataclasses.replace(pcfg, H_max=pcfg.H0))
     n_lands = acfg.lands(K) if acfg is not None else 0
 
     def round_fn(mp: Optional[MethodParams], params, state: FleetState,
@@ -392,11 +391,14 @@ def _build_round_body(model: FLModel, cfg: FLConfig,
             sel_idx, slot_live = select_slots(selected, K)
             part_k = participating[sel_idx] & slot_live
             Hk = H_cand[sel_idx]
+            # the loop runs to the cohort's largest live H, not H_max:
+            # every iteration past it would be a no-op in every slot
+            n_iters = jnp.max(jnp.where(slot_live, Hk, 0))
             xk, yk = cx[sel_idx], cy[sel_idx]
             keys = jax.random.split(k_train, K)
             client_params = jax.vmap(
-                lambda x, y, H, kk: _local_sgd(model, params, x, y, H, kk,
-                                               cfg)
+                lambda x, y, H, kk: _local_sgd(model, params, x, y, H,
+                                               n_iters, kk, cfg)
             )(xk, yk, Hk, keys)
             deliver_k = (part_k if not chaos
                          else delivered[sel_idx] & slot_live)
@@ -614,6 +616,7 @@ def _build_round_body(model: FLModel, cfg: FLConfig,
             "n_dropped": jnp.sum(new_dropped),
             "mean_H_selected": jnp.sum(jnp.where(selected, H_cand, 0)
                                        ) / jnp.maximum(jnp.sum(selected), 1),
+            "local_iters": n_iters,
             "global_loss": jnp.mean(g_loss),
             "n_available": jnp.sum(available),
             "n_charging": jnp.sum(env.charging),

@@ -1051,14 +1051,10 @@ def _run_grid_batched(model: FLModel, fleet: DeviceFleet, cx, cy,
                              autofl_ema=cfg.autofl_ema,
                              fault_cfg=scenario.faults
                              if scenario is not None else None)
-    if all(methods[n].policy == "fixed" for n in names):
-        # the shared local-SGD loop bound must cover every method in the
-        # grid: an all-fixed grid never exceeds H0, so shrink the static
-        # bound exactly like the per-method path does (a grid that mixes
-        # in adah/rewa keeps H_max — its fixed members pay masked no-op
-        # iterations beyond H0, the price of the single shared program)
-        cfg = dataclasses.replace(cfg, policy=dataclasses.replace(
-            cfg.policy, H_max=cfg.policy.H0))
+    # each cell's local-SGD loop runs to its own cohort's largest H; under
+    # the grid vmap the shared loop runs to the largest over the cells,
+    # and the cells already done keep their carry
+    #
     # a grid with any async cell compiles the async round body for every
     # cell; sync cells ride along with buffer_m = 0 (the full-cohort
     # sentinel) and reproduce their sync selections/params through the

@@ -151,8 +151,8 @@ FLEET_KWARGS = {"init_energy_mean": 0.11, "init_energy_std": 0.04,
                 "e0_frac": 0.08}
 
 HIST_KEYS = ("round_latency", "round_energy", "n_dropped",
-             "n_participating", "n_failed", "mean_H_selected", "global_loss",
-             "n_available", "n_charging", "n_online")
+             "n_participating", "n_failed", "mean_H_selected", "local_iters",
+             "global_loss", "n_available", "n_charging", "n_online")
 
 # extra per-round scalars the async round body emits (core.async_agg)
 ASYNC_HIST_KEYS = ("wall_clock", "server_version", "n_pending",

@@ -26,7 +26,7 @@ from tests.test_dynamics import GOLDEN
 N, K = 10, 4
 
 SYNC_KEYS = ("global_loss", "round_latency", "round_energy",
-             "n_participating", "n_failed", "mean_H_selected")
+             "n_participating", "n_failed", "mean_H_selected", "local_iters")
 
 
 @pytest.fixture(scope="module")

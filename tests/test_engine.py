@@ -67,7 +67,8 @@ def _parity(setup, rounds, chunk_size, atol=1e-5):
     _assert_trees_close(res.params, p_seq, atol)
     _assert_trees_close(res.state, s_seq, atol)
     for k in ("global_loss", "round_latency", "round_energy",
-              "n_participating", "n_failed", "mean_H_selected"):
+              "n_participating", "n_failed", "mean_H_selected",
+              "local_iters"):
         seq = np.asarray([h[k] for h in h_seq], np.float64)
         np.testing.assert_allclose(np.asarray(res.history[k], np.float64),
                                    seq, atol=atol, err_msg=k)
@@ -80,6 +81,29 @@ def test_scan_matches_sequential_rounds(setup):
     """Engine chunks (incl. a remainder chunk) ≡ N make_round_fn calls:
     same PRNG key folding, identical FleetState and metrics."""
     _parity(setup, rounds=5, chunk_size=3)
+
+
+@pytest.mark.parametrize("method", ["rewafl", "oort"])
+def test_local_iters_is_cohort_max_h(setup, method):
+    """`local_iters`, the local-SGD loop's trip count, is the largest H
+    over the round's selected devices: adaptive under REWAFL, H0 under
+    a fixed-H method."""
+    model, fleet, cx, cy, cfg = setup
+    res = eng.run_rounds(model, fleet, cx, cy, cfg, METHODS[method],
+                         rounds=8, key=jax.random.PRNGKey(7),
+                         params=model.init(jax.random.PRNGKey(0)),
+                         ecfg=eng.EngineCfg(chunk_size=4))
+    h = res.history
+    # with no failures every selected device trained at its history H
+    assert not np.asarray(h["n_failed"]).any()
+    sel = np.asarray(h["selected"])
+    H = np.asarray(h["H"])
+    want = np.where(sel, H, 0).max(axis=1)
+    np.testing.assert_array_equal(np.asarray(h["local_iters"]), want)
+    if method == "oort":
+        assert (want == cfg.policy.H0).all()
+    else:
+        assert want.max() > cfg.policy.H0
 
 
 @pytest.mark.slow
@@ -262,7 +286,7 @@ def test_method_batched_grid_matches_per_method(setup):
             np.asarray(hb["selected"]), np.asarray(solo["selected"]),
             err_msg=f"{m}: selection masks diverged")
         for k in ("global_loss", "round_energy", "round_latency",
-                  "mean_H_selected", "n_participating"):
+                  "mean_H_selected", "local_iters", "n_participating"):
             np.testing.assert_allclose(
                 np.asarray(hb[k], np.float64),
                 np.asarray(solo[k], np.float64), atol=1e-5, err_msg=f"{m}/{k}")
@@ -405,7 +429,7 @@ def test_streaming_matches_dense_history_reductions(setup):
                                                telemetry=tcfg), **kw)
     # dense-mode scalar history is bitwise-unchanged by the refactor
     for k in ("global_loss", "round_energy", "round_latency",
-              "n_participating", "mean_H_selected"):
+              "n_participating", "mean_H_selected", "local_iters"):
         np.testing.assert_array_equal(np.asarray(dense.history[k]),
                                       np.asarray(stream.history[k]),
                                       err_msg=k)

@@ -4,8 +4,6 @@ rounds on a small fleet/dataset (the paper's system end-to-end).
 Tier-1 runs the structurally distinct methods (rewafl = rea+rewa policy,
 oort = ε-greedy+fixed); the remaining baselines ride the slow tier. The
 jitted round fn per method is compiled once and shared module-wide."""
-import dataclasses
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -156,11 +154,9 @@ def test_under_k_selection_no_duplicate_weights(setup):
     assert sel.sum() == 2 and sel[0] and sel[5]
     assert int(m["n_participating"]) == 2
     # reference: each client trained once, each weight used once
-    cfg_ref = dataclasses.replace(
-        cfg, policy=dataclasses.replace(cfg.policy, H_max=cfg.policy.H0))
     H0 = jnp.asarray(cfg.policy.H0, jnp.int32)
-    upd = [_local_sgd(model, params, cx[i], cy[i], H0,
-                      jax.random.PRNGKey(123), cfg_ref) for i in (0, 5)]
+    upd = [_local_sgd(model, params, cx[i], cy[i], H0, H0,
+                      jax.random.PRNGKey(123), cfg) for i in (0, 5)]
     client_params = jax.tree.map(lambda a, b: jnp.stack([a, b]), *upd)
     weights = fleet.data_size[jnp.array([0, 5])].astype(jnp.float32)
     expected = _fedavg(params, client_params, weights)
@@ -174,6 +170,85 @@ def test_under_k_selection_no_duplicate_weights(setup):
     untouched[[0, 5]] = False
     np.testing.assert_array_equal(np.asarray(new_state.last_stat)[untouched],
                                   np.asarray(state.last_stat)[untouched])
+
+
+def _static_local_sgd(model, params, x, y, H, key, cfg):
+    """Reference: local SGD over all H_max iterations, those at or past
+    the slot's H masked to no-ops."""
+    n = x.shape[0]
+    grad_fn = jax.grad(model.loss)
+
+    def body(it, p):
+        k = jax.random.fold_in(key, it)
+        idx = jax.random.randint(k, (cfg.batch_size,), 0, n)
+        g = grad_fn(p, {"x": x[idx], "y": y[idx]})
+        live = (it < H).astype(jnp.float32)
+        return jax.tree.map(lambda pp, gg: pp - cfg.lr * live * gg, p, g)
+
+    return jax.lax.fori_loop(0, cfg.policy.H_max, body, params)
+
+
+@pytest.mark.parametrize("task", ["cnn@mnist", "lstm@shakespeare"])
+@pytest.mark.parametrize("H_slots,n_live", [
+    ((1, 6, 3, 2, 4, 6), 4),   # H_max in a live slot: the bound is H_max
+    ((1, 3, 2, 1, 6, 5), 4),   # the pad slots hold the largest H
+], ids=["live_hmax", "pad_hmax"])
+def test_cohort_bound_matches_static_hmax_loop(task, H_slots, n_live):
+    """Local SGD bounded by the cohort's largest live H leaves every live
+    slot's parameters bit-identical to the old loop over all H_max
+    iterations: the iterations it drops were masked no-ops."""
+    from repro.core.round import _local_sgd
+    Kc = len(H_slots)
+    model = make_fl_model(task, small=True)
+    cx, cy, _ = build_task(task, Kc, 0.8, per_client=12, n_test=8)
+    cfg = FLConfig(n_select=Kc, batch_size=4, lr=0.05,
+                   policy=PolicyCfg(H0=2, H_max=6))
+    params = model.init(jax.random.PRNGKey(0))
+    Hk = jnp.asarray(H_slots, jnp.int32)
+    slot_live = jnp.arange(Kc) < n_live
+    keys = jax.random.split(jax.random.PRNGKey(3), Kc)
+
+    @jax.jit
+    def bounded(cx, cy, Hk, keys):
+        n_iters = jnp.max(jnp.where(slot_live, Hk, 0))
+        return jax.vmap(lambda x, y, H, kk: _local_sgd(
+            model, params, x, y, H, n_iters, kk, cfg))(cx, cy, Hk, keys)
+
+    @jax.jit
+    def static(cx, cy, Hk, keys):
+        return jax.vmap(lambda x, y, H, kk: _static_local_sgd(
+            model, params, x, y, H, kk, cfg))(cx, cy, Hk, keys)
+
+    got = bounded(cx, cy, Hk, keys)
+    want = static(cx, cy, Hk, keys)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a)[:n_live],
+                                      np.asarray(b)[:n_live])
+    # slot 0 (H 1) moved off the initial model: the comparison bites
+    moved = [np.any(np.asarray(a)[0] != np.asarray(p))
+             for a, p in zip(jax.tree.leaves(got), jax.tree.leaves(params))]
+    assert any(moved)
+
+
+def test_local_iters_ignores_pad_slots(setup, round_fns):
+    """With fewer than K selected, the pad slots gather device 0; its H
+    must not set the loop's trip count, which is the largest H of the
+    selected devices."""
+    model, fleet, cx, cy, cfg = setup
+    state = init_fleet_state(fleet, H0=cfg.policy.H0)
+    # device 0 carries H_max but cannot be selected; only 3 and 5 can
+    state = state._replace(
+        H=state.H.at[0].set(cfg.policy.H_max),
+        residual_energy=fleet.battery_j.astype(jnp.float32),
+        dropped=jnp.ones(N, bool).at[jnp.array([3, 5])].set(False))
+    rf = round_fns("rewafl")
+    params = model.init(jax.random.PRNGKey(0))
+    _, _, _, m = rf(params, state, init_env_state(fleet),
+                    jax.random.PRNGKey(5), jnp.asarray(0, jnp.int32))
+    sel = np.asarray(m["selected"])
+    assert sel.sum() == 2 and not sel[0]
+    h_sel = np.asarray(m["H"])[sel]
+    assert int(m["local_iters"]) == h_sel.max() < cfg.policy.H_max
 
 
 def test_fedavg_identity_when_no_participants(setup, round_fns):
