@@ -25,13 +25,19 @@ history and in the carries:
   the reference trains the chunk's rounds from the first carry's model,
   with the selections, participation and local-iteration counts the
   program decided (teacher-forced from its history and carries), and
-  the change of each parameter leaf over the chunk is compared by its
-  norm: |‖program's change‖ − ‖reference's change‖| over the larger of
-  the reference's and the median leaf's, worst leaf.
+  the change of each trainable parameter leaf over the chunk is
+  compared by its norm: |‖program's change‖ − ‖reference's change‖|
+  over the larger of the reference's and the median trainable leaf's,
+  worst leaf;
+* `frozen`, where the configuration declares `trainable`: the frozen
+  leaves whose fingerprint in a tapped carry differs from the initial
+  model's, counted over every tapped chunk so far. The program must
+  never move them; it is compared exactly, at the limit its limits file
+  gives or else 0.
 
 Each number is the worst over the compared rounds. A cell compares the
-numbers that have a limit in `limits/<workload>.json`; the others are
-read and printed only.
+numbers that have a limit in `limits/<workload>.json` (and `frozen`);
+the others are read and printed only.
 """
 from __future__ import annotations
 
@@ -39,6 +45,7 @@ from types import SimpleNamespace
 from typing import Dict
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from bench import reference
@@ -93,9 +100,45 @@ def readings(out, ref, eps_th: float) -> Dict[str, float]:
     }
 
 
-def norm_gap(p0, p_out, p_ref) -> float:
-    """Worst leaf of |‖p_out − p0‖ − ‖p_ref − p0‖| over the larger of
-    ‖p_ref − p0‖ and the median leaf's."""
+_UINT = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}
+
+
+@jax.jit
+def fingerprint(tree) -> jax.Array:
+    """(leaves, 2) uint32 on the device: the wrapping sum of each leaf's
+    bits, and the same weighted by a hash of each element's position, so
+    that a leaf whose values move or swap reads another."""
+    def one(a):
+        bits = jax.lax.bitcast_convert_type(
+            a, _UINT[a.dtype.itemsize]).astype(jnp.uint32).ravel()
+        w = (jnp.arange(bits.size, dtype=jnp.uint32)
+             * jnp.uint32(2654435761) + jnp.uint32(1))
+        return jnp.stack([jnp.sum(bits, dtype=jnp.uint32),
+                          jnp.sum(bits * w, dtype=jnp.uint32)])
+    leaves = jax.tree.leaves(tree)
+    if not leaves:
+        return jnp.zeros((0, 2), jnp.uint32)
+    return jnp.stack([one(a) for a in leaves])
+
+
+def frozen_moved(fp0: np.ndarray, fps) -> list:
+    """For each tapped carry's fingerprint in `fps` (in chunk order), the
+    frozen leaves whose fingerprint differed from the initial model's
+    `fp0` there or at an earlier one."""
+    moved = np.zeros(fp0.shape[0], bool)
+    out = []
+    for fp in fps:
+        moved |= (np.asarray(fp) != fp0).any(axis=1)
+        out.append(int(moved.sum()))
+    return out
+
+
+def norm_gap(p0, p_out, p_ref, trainable=None) -> float:
+    """Worst trainable leaf of |‖p_out − p0‖ − ‖p_ref − p0‖| over the
+    larger of ‖p_ref − p0‖ and the median trainable leaf's."""
+    p0, p_out, p_ref = (reference.split(p, trainable)[1]
+                        for p in (p0, p_out, p_ref))
+
     def norms(p):
         return np.array([np.linalg.norm(np.asarray(a, np.float64)
                                         - np.asarray(b, np.float64))
@@ -139,7 +182,7 @@ def model_reading(cell, ref_model, fleet_np, cx, cy, snap, snap_next, hist,
 
     p_ref = follow("reference")
     p_out = snap_next.params if who == "program" else follow("control")
-    return norm_gap(snap.params, p_out, p_ref)
+    return norm_gap(snap.params, p_out, p_ref, cell.config.get("trainable"))
 
 
 def program_out(hist, r: int):
@@ -186,17 +229,28 @@ def over(value: float, limit: float) -> bool:
 
 
 def check(cell, ref_model, fleet_np, cx, cy, snaps, hist, log=None,
-          who: str = "program"):
+          who: str = "program", fp0=None):
     """({number: {"value": worst reading, "limit": limit}}, rounds with a
     reading over its limit) for the program's outputs (or the
-    control's, `who="control"`)."""
+    control's, `who="control"`). `fp0`, the initial model's frozen
+    fingerprint, adds `frozen` from the snapshots' fingerprints."""
+    limits = dict(cell.limits)
+    moved = []
+    if who != "program":   # the control's frozen leaves are the reference's
+        limits.pop("frozen", None)
+    elif fp0 is not None:
+        limits.setdefault("frozen", 0)
+        moved = frozen_moved(fp0, [snaps[c].fingerprint
+                                   for c in sorted(snaps)])
     per_round = []
-    for c in sorted(snaps):
+    for i, c in enumerate(sorted(snaps)):
         r = round_readings(cell, ref_model, fleet_np, cx, cy, snaps[c],
                            hist, who)
         if c + 1 in snaps:
             r["model"] = model_reading(cell, ref_model, fleet_np, cx, cy,
                                        snaps[c], snaps[c + 1], hist, who)
+        if moved:
+            r["frozen"] = float(moved[i])
         per_round.append(r)
         if log is not None:
             print(f"compared_round={snaps[c].round} who={who} "
@@ -207,7 +261,7 @@ def check(cell, ref_model, fleet_np, cx, cy, snaps, hist, log=None,
         print(f"worst who={who} "
               + " ".join(f"{k}={v!r}" for k, v in w.items()), file=log,
               flush=True)
-    bad = sum(any(over(r[k], v) for k, v in cell.limits.items() if k in r)
+    bad = sum(any(over(r[k], v) for k, v in limits.items() if k in r)
               for r in per_round)
-    return ({k: {"value": w[k], "limit": v}
-             for k, v in cell.limits.items()}, bad)
+    return ({k: {"value": w[k], "limit": v} for k, v in limits.items()},
+            bad)

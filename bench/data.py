@@ -14,6 +14,13 @@ that set-up does not spend tens of seconds in NumPy on the host:
   mixes two global Dirichlet(0.3) transition matrices by its own weight
   (LEAF's one speaking role per client). Targets are the next character,
   so the labels array is unused and zero.
+* `tokens`: token sequences over a vocabulary of real size, with tables
+  of O(V): two seeded permutations π0 and π1 of the ids and a mixing
+  weight m ~ U(0, 1) per client. Each next token is, with probability
+  `noise`, drawn from a Zipf(`zipf`) law over a third seeded
+  permutation of the ids; otherwise it is π0[prev] with probability m
+  and π1[prev] else. The first token is drawn from the Zipf law. Labels
+  are unused and zero, as for `chars`.
 
 Samples are drawn fresh for every slot (the program's partitioner draws
 with replacement from a pool); the label law per client is the same.
@@ -38,6 +45,10 @@ def make_client_data(key, kind: str, n_clients: int, per_client: int,
         return _chars(key, n_clients, per_client, n_test,
                       int(data_cfg["vocab"]), int(data_cfg["seq_len"]),
                       float(data_cfg["dirichlet"]))
+    if kind == "tokens":
+        return _tokens(key, n_clients, per_client, n_test,
+                       int(data_cfg["vocab"]), int(data_cfg["seq_len"]),
+                       float(data_cfg["zipf"]), float(data_cfg["noise"]))
     raise ValueError(f"unknown data kind {kind!r}")
 
 
@@ -92,6 +103,42 @@ def _chars(key, S, n, n_test, V, T, conc):
     u = jax.random.uniform(ku, (T, R, n))
     _, seq = jax.lax.scan(step, s0, u)          # (T, R, n)
     seq = jnp.moveaxis(seq, 0, -1).astype(jnp.int32)  # (R, n, T)
+    cx = seq[:S]
+    tx = seq[S:].reshape(-1, T)[:n_test]
+    return (cx, jnp.zeros((S, n), jnp.int32), tx,
+            jnp.zeros((tx.shape[0],), jnp.int32))
+
+
+def token_law(key, R: int, V: int, zipf: float):
+    """(π0, π1, Zipf ids, Zipf CDF, m): the `tokens` law of R clients
+    (the test roles included), from the data key."""
+    kp0, kp1, kpz, km, _ = jax.random.split(key, 5)
+    ranks = jnp.arange(1, V + 1, dtype=jnp.float32)
+    cdf = jnp.cumsum(ranks ** -zipf)
+    return (jax.random.permutation(kp0, V), jax.random.permutation(kp1, V),
+            jax.random.permutation(kpz, V), cdf / cdf[-1],
+            jax.random.uniform(km, (R,)))
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7))
+def _tokens(key, S, n, n_test, V, T, zipf, noise):
+    ks = jax.random.split(key, 5)[4]
+    R = S + -(-n_test // n)
+    pi0, pi1, zids, cdf, m = token_law(key, R, V, zipf)
+
+    def draw_zipf(u):
+        return zids[jnp.minimum(jnp.searchsorted(cdf, u), V - 1)]
+
+    def step(prev, t):
+        u = jax.random.uniform(jax.random.fold_in(ks, t), (3, R, n))
+        nxt = jnp.where(u[1] < m[:, None], pi0[prev], pi1[prev])
+        nxt = jnp.where(u[0] < noise, draw_zipf(u[2]), nxt)
+        return nxt, nxt
+
+    first = draw_zipf(jax.random.uniform(jax.random.fold_in(ks, 0), (R, n)))
+    _, rest = jax.lax.scan(step, first, jnp.arange(1, T))  # (T-1, R, n)
+    seq = jnp.concatenate([first[None], rest]).astype(jnp.int32)
+    seq = jnp.moveaxis(seq, 0, -1)                           # (R, n, T)
     cx = seq[:S]
     tx = seq[S:].reshape(-1, T)[:n_test]
     return (cx, jnp.zeros((S, n), jnp.int32), tx,

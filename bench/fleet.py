@@ -5,6 +5,11 @@ here so that the benchmark hands the program arrays it made itself: five
 phone and laptop types in equal shares (a remainder round-robins over
 them), initial battery a clipped normal share of capacity, half of the
 devices in a poor radio environment, Poisson local data sizes.
+
+A traffic file may replace the table's time per local iteration, set at
+the paper's task scale, by `t_iter_s`: one measured time per device
+type, in the order of `DEVICE_TYPES`, given only with `t_iter_source`,
+where those times were measured.
 """
 from __future__ import annotations
 
@@ -27,14 +32,21 @@ FIELDS = ("type_id", "t_iter", "p_compute", "p_tx", "battery_j",
 def draw_fleet(n: int, seed: int, *, frac_low_rate: float = 0.5,
                e0_frac: float = 0.05, init_energy_mean: float = 0.5,
                init_energy_std: float = 0.25, data_size: int = 500,
-               rate_sigma: float = 0.3) -> dict:
+               rate_sigma: float = 0.3, t_iter_s=None,
+               t_iter_source: str = "") -> dict:
     """{field: (n,) array} in the order and dtypes of `FIELDS`."""
+    if (t_iter_s is None) != (not t_iter_source):
+        raise ValueError("t_iter_s and t_iter_source go together")
     rng = np.random.RandomState(seed)
     nt = len(DEVICE_TYPES)
     per, rem = divmod(n, nt)
     type_id = np.concatenate([np.repeat(np.arange(nt), per),
                               np.arange(rem)]).astype(np.int32)
     table = np.asarray([t[1:] for t in DEVICE_TYPES], np.float64)
+    if t_iter_s is not None:
+        if len(t_iter_s) != nt:
+            raise ValueError(f"t_iter_s needs {nt} times, one a device type")
+        table[:, 0] = t_iter_s
     t_iter, p_comp, p_tx, battery, r_hi, r_lo = (
         table[type_id, j].astype(np.float32) for j in range(6))
     init_frac = np.clip(rng.normal(init_energy_mean, init_energy_std, n),

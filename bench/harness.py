@@ -17,7 +17,11 @@ once the window is over, so one call holds set-up and window.
 A tap on the engine's chunk function copies the carry (model, fleet
 state, round key) to the host before the chunks the traffic names, so
 that the reference can recompute their first round from the same carry
-once the window has closed (`reference.py`, `compare.py`).
+once the window has closed (`reference.py`, `compare.py`). Where the
+configuration declares `trainable`, the tap copies the trainable leaves
+only, and a fingerprint of each frozen leaf made on the device; the
+reference rebuilds the frozen leaves from the init seed after the
+window, and `compare.py` counts those the program moved.
 """
 from __future__ import annotations
 
@@ -43,7 +47,7 @@ from repro.obs.trace import Tracer, tracing
 from repro.sim.devices import DeviceFleet
 from repro.sim.dynamics import get_scenario
 
-from bench import compare, spec
+from bench import compare, reference, spec
 from bench import trace as trace_mod
 from bench.data import make_client_data
 from bench.fleet import FIELDS, draw_fleet
@@ -120,17 +124,20 @@ class Snapshot:
     state: Dict[str, np.ndarray]
     key: np.ndarray
     round: int
+    fingerprint: Optional[np.ndarray] = None   # of the frozen leaves
 
 
 class Tap:
     """Wraps the engine's chunk-function factory: passes every call
     through, and copies the carry to the host before the chunks in
-    `chunks` (0 = the first). Keeps the jitted function and the abstract
-    arguments of its calls, for the program's compiled text."""
+    `chunks` (0 = the first): with `trainable` given, its leaves and the
+    frozen leaves' fingerprint only. Keeps the jitted function and the
+    abstract arguments of its calls, for the program's compiled text."""
 
-    def __init__(self, engine_module, chunks):
+    def __init__(self, engine_module, chunks, trainable=None):
         self.engine = engine_module
         self.chunks = set(chunks)
+        self.trainable = trainable
         self.snaps: Dict[int, Snapshot] = {}
         self.fn = None
         self.abstract = None
@@ -166,10 +173,15 @@ class Tap:
                     sharding=x.sharding if x.committed else None), args)
         if self.calls in self.chunks:
             params, state, _, _, _, _, key, start = args[:8]
-            host = jax.device_get((params, state._asdict(), key, start))
+            fp = None
+            if self.trainable is not None:
+                frozen, params = reference.split(params, self.trainable)
+                fp = compare.fingerprint(frozen)
+            host = jax.device_get((params, state._asdict(), key, start,
+                                   fp))
             self.snaps[self.calls] = Snapshot(host[0], host[1],
                                               np.asarray(host[2]),
-                                              int(host[3]))
+                                              int(host[3]), host[4])
         self.calls += 1
 
     def compiled(self):
@@ -298,8 +310,15 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     cx, cy, tx, ty = make_client_data(
         jax.random.PRNGKey(seeds["data"]), dcfg["kind"], S,
         int(tr["per_client"]), float(tr["lam"]), int(dcfg["n_test"]), dcfg)
-    params0 = jax.jit(lambda k: ref_model.init(k, mc))(
-        jax.random.PRNGKey(seeds["init"]))
+    init = jax.jit(lambda k: ref_model.init(k, mc))
+    params0 = init(jax.random.PRNGKey(seeds["init"]))
+    trainable = mc.get("trainable")
+    frozen0, train0 = reference.split(params0, trainable)
+    leaf_sizes = [int(np.prod(a.shape)) for a in jax.tree.leaves(train0)]
+    fp0 = None
+    if trainable is not None:
+        fp0 = np.asarray(compare.fingerprint(frozen0))
+    del frozen0, train0
     jax.block_until_ready((cx, params0))
     marks["inputs"] = time.time() - t0
     model = _program_model(cell)
@@ -312,7 +331,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     evaluate = make_eval_fn(model, tx, ty)
     compare_chunks = sorted(int(c) for c in tr["compare_chunks"])
     trace_chunks = int(tr["trace_chunks"]) if trace else 0
-    tap = Tap(engine, compare_chunks)
+    tap = Tap(engine, compare_chunks, trainable)
     warm = int(tr["warm_chunks"])
     window = Window(evaluate, seconds, warm, max(compare_chunks + [warm]),
                     trace_chunks, t0, prof_dir if trace else None, counter)
@@ -337,7 +356,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     stats = [d.memory_stats() or {} for d in devs]
     peak = [int(s.get("peak_bytes_in_use", 0)) for s in stats]
     hist = {k: np.asarray(v) for k, v in res.history.items()}
-    del res
+    del res, params0
     _say(log, platform=devs[0].platform, device_kind=repr(kind),
          device_count=len(devs), compiles_in_window=window.compiles,
          peak_bytes_per_device=peak)
@@ -362,7 +381,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     if trace:
         out["metrics"], breakdown, busy, win_s = _per_layer(
             cell, compiled.as_text(), prof_dir, hist, warm, trace_chunks,
-            chunk, kind, len(devs), ref_model, params0)
+            chunk, kind, len(devs), ref_model, leaf_sizes)
         device.update(busy_s=busy, window_s=win_s)
     else:
         out["metrics"] = {
@@ -373,12 +392,24 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     if trace:
         out["breakdown"] = breakdown
 
+    if trainable is not None:
+        # the tap kept no frozen leaf and the carry was donated: they
+        # come again from the init seed, checked against set-up's
+        # fingerprint
+        frozen = reference.split(init(jax.random.PRNGKey(seeds["init"])),
+                                 trainable)[0]
+        if not np.array_equal(np.asarray(compare.fingerprint(frozen)),
+                              fp0):
+            raise RuntimeError("the frozen leaves rebuilt from the init "
+                               "seed differ from set-up's")
+        for s in tap.snaps.values():
+            s.params = reference.merge(frozen, s.params)
     if control:
         out["control_checks"] = compare.check(
             cell, ref_model, fleet_np, cx, cy, tap.snaps, hist, log=log,
             who="control")[0]
     checks, bad_rounds = compare.check(cell, ref_model, fleet_np, cx, cy,
-                                       tap.snaps, hist, log=log)
+                                       tap.snaps, hist, log=log, fp0=fp0)
     checks["compiles"] = {"value": window.compiles, "limit": 0}
     out["correct"] = not any(compare.over(c["value"], c["limit"])
                              for c in checks.values())
@@ -394,7 +425,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
 
 
 def _per_layer(cell, hlo_text, prof_dir, hist, warm, traced_chunks, chunk,
-               kind, n_chips, ref_model, params0):
+               kind, n_chips, ref_model, leaf_sizes):
     hlo = trace_mod.parse_hlo(hlo_text)
     tr = trace_mod.load(trace_mod.find_xplane(prof_dir), hlo)
     rounds = traced_chunks * chunk
@@ -409,7 +440,7 @@ def _per_layer(cell, hlo_text, prof_dir, hist, warm, traced_chunks, chunk,
         forward_flops=ref_model.forward_flops(cell.config),
         train_flops=ref_model.train_flops(cell.config),
         history={k: hist[k][lo:hi] for k in ("selected", "mean_H_selected")},
-        leaf_sizes=[int(np.prod(a.shape)) for a in jax.tree.leaves(params0)],
+        leaf_sizes=leaf_sizes,
         peaks=peaks_for(kind), device_kind=kind, n_chips=n_chips)
     metrics = {}
     readers = spec.metric_readers(cell)

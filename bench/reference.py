@@ -18,6 +18,17 @@ or weight it made besides that carry:
 with each round's selection and local-iteration counts taken as the
 program decided them, for the comparison of the aggregated model.
 
+A configuration may declare `trainable`: the top-level keys of the
+parameter tree that devices train and upload. The other leaves are
+frozen: local SGD closes over them, unbatched, and FedAvg hands them
+back untouched. Absent, the whole tree is trainable.
+
+The probe runs PROBE_BLOCK devices (where that divides S) to a call of
+the configuration's `per_sample_loss`, and local SGD all K slots in one
+vmap. A configuration whose activations would not fit at that many
+samples bounds them inside its own `per_sample_loss` (a `lax.map` over
+groups of samples, under `jax.checkpoint` for the gradient).
+
 Random draws follow the campaign's key exactly as the round states them
 (one split per round, then rates / selection / training keys, one key
 per training slot), so a device trains on the same minibatches here.
@@ -58,6 +69,24 @@ def _dtype(precision: str):
 MATMUL = "default"    # the precision the configurations state
 
 
+def split(params: dict, trainable):
+    """(frozen, trainable) subtrees of `params` by its top-level keys;
+    with `trainable` None every leaf is trainable."""
+    if trainable is None:
+        return {}, params
+    missing = set(trainable) - set(params)
+    if missing:
+        raise KeyError(f"trainable keys {sorted(missing)} are not in the "
+                       f"parameter tree's {sorted(params)}")
+    return ({k: v for k, v in params.items() if k not in trainable},
+            {k: v for k, v in params.items() if k in trainable})
+
+
+def merge(frozen: dict, trainable: dict) -> dict:
+    """The whole tree again from `split`'s two parts."""
+    return {**frozen, **trainable}
+
+
 @functools.partial(jax.jit, static_argnums=(0, 4, 5, 6))
 def probe_losses(model, params, cx, cy, probe: int, dtype,
                  matmul: str) -> jax.Array:
@@ -77,16 +106,19 @@ def probe_losses(model, params, cx, cy, probe: int, dtype,
     return jax.lax.map(block, (px, py)).reshape(S)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 5, 6, 7, 8, 9))
-def _local_sgd(model, params, xk, yk, hk_keys, H_max, batch, lr, dtype,
-               matmul):
-    """Per-slot SGD from the common model: H_k live iterations each."""
+@functools.partial(jax.jit, static_argnums=(0, 6, 7, 8, 9, 10))
+def _local_sgd(model, frozen, params, xk, yk, hk_keys, H_max, batch, lr,
+               dtype, matmul):
+    """Per-slot SGD of the trainable `params` from the common model, the
+    `frozen` leaves held: H_k live iterations each."""
     Hk, keys = hk_keys
     n = xk.shape[1]
+    base = jax.tree.map(lambda a: a.astype(dtype), frozen)
 
     def loss(p, x, y):
         with jax.default_matmul_precision(matmul):
-            return jnp.mean(model.per_sample_loss(p, x, y, dtype))
+            return jnp.mean(model.per_sample_loss(merge(base, p), x, y,
+                                                  dtype))
 
     def one(x, y, H, key):
         p0 = jax.tree.map(lambda a: a.astype(dtype), params)
@@ -144,7 +176,8 @@ def run_round(model, mcfg: dict, fl: dict, fleet: Dict[str, np.ndarray],
     H_cand = np.clip(np.where(eps >= f32(fl["eps_th"]), grown,
                               H.astype(f32)), 1, fl["H_max"]).astype(np.int32)
 
-    # latency / energy estimates and Eqn (2)
+    # latency / energy estimates and Eqn (2); n_params counts what a
+    # device uploads
     bits = f32(32 * model.n_params(mcfg))
     t_comp = H_cand.astype(f32) * fleet["t_iter"]
     t_comm = bits / np.maximum(rates, f32(1))
@@ -162,8 +195,8 @@ def run_round(model, mcfg: dict, fl: dict, fleet: Dict[str, np.ndarray],
     selected = _top_k(util, min(K, S))
     participating = selected & (e < head)
     new_params = train_and_aggregate(
-        model, fl, fleet, cx, cy, params, selected, participating, H_cand,
-        k_train, K, dtype, matmul)
+        model, mcfg, fl, fleet, cx, cy, params, selected, participating,
+        H_cand, k_train, K, dtype, matmul)
     g_after = np.asarray(probe_losses(model, new_params, cx, cy, probe,
                                       dtype, matmul), np.float64)
     new_H = np.where(participating, H_cand, H).astype(np.int32)
@@ -172,31 +205,33 @@ def run_round(model, mcfg: dict, fl: dict, fleet: Dict[str, np.ndarray],
                     util, eps)
 
 
-def train_and_aggregate(model, fl: dict, fleet: Dict[str, np.ndarray], cx,
-                        cy, params, selected: np.ndarray,
-                        participating: np.ndarray, H: np.ndarray, k_train,
-                        K: int, dtype, matmul: str):
+def train_and_aggregate(model, mcfg: dict, fl: dict,
+                        fleet: Dict[str, np.ndarray], cx, cy, params,
+                        selected: np.ndarray, participating: np.ndarray,
+                        H: np.ndarray, k_train, K: int, dtype, matmul: str):
     """Local SGD of the K training slots (selected devices in index
     order, H[i] live iterations each, one key per slot) and the
-    data-size-weighted FedAvg of the participants' models."""
+    data-size-weighted FedAvg of the participants' trainable leaves; the
+    frozen leaves come back untouched."""
     f32 = np.float32
+    frozen, params = split(params, mcfg.get("trainable"))
     sel_idx = np.flatnonzero(selected)
     live = np.zeros(K, bool)
     live[:len(sel_idx)] = True
     sel_idx = np.concatenate([sel_idx, np.zeros(K - len(sel_idx), int)])
     part_k = participating[sel_idx] & live
     keys = jax.random.split(k_train, K)
-    client = _local_sgd(model, params, cx[sel_idx], cy[sel_idx],
+    client = _local_sgd(model, frozen, params, cx[sel_idx], cy[sel_idx],
                         (jnp.asarray(H[sel_idx]), keys),
                         int(fl["H_max"]), int(fl["batch_size"]),
                         float(fl["lr"]), dtype, matmul)
     w = fleet["data_size"][sel_idx].astype(f32) * part_k.astype(f32)
     if w.sum() > 0:
         wn = jnp.asarray(w / max(w.sum(), f32(1e-9)))
-        return jax.tree.map(
+        return merge(frozen, jax.tree.map(
             lambda c: jnp.tensordot(wn, c.astype(jnp.float32),
-                                    axes=1).astype(dtype), client)
-    return jax.tree.map(lambda a: a.astype(dtype), params)
+                                    axes=1).astype(dtype), client))
+    return merge(frozen, jax.tree.map(lambda a: a.astype(dtype), params))
 
 
 def follow_rounds(model, mcfg: dict, fl: dict, fleet: Dict[str, np.ndarray],
@@ -207,14 +242,14 @@ def follow_rounds(model, mcfg: dict, fl: dict, fleet: Dict[str, np.ndarray],
     each round's selection, participation and local-iteration counts
     given ((R, S) arrays, the program's own decisions), the training
     computed here: the same round keys, the same minibatches, local SGD
-    and FedAvg in `precision`."""
+    and FedAvg of the trainable leaves in `precision`."""
     dtype, matmul = _dtype(precision), MATMUL
     p = jax.tree.map(lambda a: jnp.asarray(a).astype(dtype), params)
     key = jnp.asarray(key)
     for r in range(selected.shape[0]):
         key, kr = jax.random.split(key)
         _, _, k_train = jax.random.split(kr, 3)
-        p = train_and_aggregate(model, fl, fleet, cx, cy, p, selected[r],
-                                participating[r], H[r], k_train, K, dtype,
-                                matmul)
+        p = train_and_aggregate(model, mcfg, fl, fleet, cx, cy, p,
+                                selected[r], participating[r], H[r],
+                                k_train, K, dtype, matmul)
     return p

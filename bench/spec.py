@@ -69,7 +69,11 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
 
 
 def reference_model(cell: Cell):
-    """The configuration's plain reference module (`reference` key)."""
+    """The configuration's plain reference module (`reference` key):
+    `init(key, cfg)`, `per_sample_loss(params, x, y, dtype)`,
+    `n_params(cfg)`, the parameters a device uploads (the `trainable`
+    leaves where the configuration declares them, else all), and the
+    per-sample `forward_flops(cfg)` and `train_flops(cfg)`."""
     return load_module(cell.config_dir / cell.config["reference"],
                        f"bench_ref_{cell.config['name']}")
 
